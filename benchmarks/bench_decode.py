@@ -214,6 +214,9 @@ def _bench_mesh() -> List[Row]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=8").strip()
+    # the simulated mesh is CPU devices; never let the child reach for
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         [root, os.path.join(root, "src"),

@@ -33,12 +33,14 @@ def _suite_artifact(suite: str, rows) -> dict:
 
 def main() -> None:
     from benchmarks.paper_tables import ALL
+    from repro.launch.compile_cache import use_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("suites", nargs="*", default=[],
                     help=f"suites to run (default all): {sorted(ALL)}")
     ap.add_argument("--json-dir", default=None,
                     help="write BENCH_<suite>.json artifacts here")
     args = ap.parse_args()
+    use_compile_cache()
     wanted = args.suites or list(ALL)
     out_dir = pathlib.Path(args.json_dir) if args.json_dir else None
     if out_dir:

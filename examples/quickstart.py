@@ -19,9 +19,11 @@ from repro.core import (HwConfig, plan, simulate_dense, simulate_gated,
 from repro.core.blockmap import block_skip_fraction
 from repro.core.masks import SyntheticTrace, synthetic_masks, topk_mask
 from repro.kernels.ops import sata_attention, sata_attention_reference
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     # --- 1-3: the paper's evaluation plane --------------------------------
     w = WORKLOADS["kvt_tiny"]
     masks = synthetic_masks(0, w.trace, w.n_heads)

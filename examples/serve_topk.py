@@ -18,8 +18,8 @@ without the QoS ladder completes EVERY request with zero
 requeues/timeouts when the ladder absorbs the pressure as per-slot
 quality rungs, a corrupted host-swap payload is detected at the
 swap-in checksum gate and quarantined (victim recovers by re-prefill),
-and a child process killed mid-serve resumes from its checkpoint in
-THIS process with bitwise-equal outputs.
+and a run killed mid-serve resumes from its checkpoint with
+bitwise-equal outputs.
 
 With ``--retire`` the demo serves a workload whose live prefixes do
 not fit the pool: cascade token retirement frees the coldest blocks'
@@ -38,12 +38,12 @@ Run:  PYTHONPATH=src python examples/serve_topk.py
 """
 import argparse
 import dataclasses
-import os
-import subprocess
-import sys
 import tempfile
 
+import jax
+
 from repro.configs.archs import SMOKE
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.faults import FaultPlan
 from repro.launch.serve import (ResilienceOptions, ServeKilled,
                                 ServeOptions, serve, serve_replicated)
@@ -104,14 +104,10 @@ def main():
                     help="overload-resilience scenario: seeded load "
                          "spikes absorbed by the QoS degradation "
                          "ladder, a corrupted swap payload quarantined "
-                         "at the checksum gate, and a cross-process "
+                         "at the checksum gate, and a mid-serve "
                          "kill/resume from checkpoint — all asserted")
-    # internal: overload child mode (run to the kill step, then die)
-    ap.add_argument("--_ckpt-dir", dest="_ckpt_dir", default=None,
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--_kill-at", dest="_kill_at", type=int, default=None,
-                    help=argparse.SUPPRESS)
     args = ap.parse_args()
+    use_compile_cache()
     cfg = dataclasses.replace(
         SMOKE["qwen3-4b"],
         topk_impl="bisect",         # bisect thresholds (the SATA predicate)
@@ -127,11 +123,7 @@ def main():
     if args.replicas:
         return replicated_demo(cfg, args.replicas)
     if args.overload is not None:
-        child_args = ["--summary", args.summary,
-                      "--replan-mode", args.replan_mode]
-        return overload_demo(cfg, args.overload, child_args,
-                             ckpt_dir=args._ckpt_dir,
-                             kill_at=args._kill_at)
+        return overload_demo(cfg, args.overload)
     if args.faults is not None:
         return faults_demo(cfg, args.faults)
     if args.retire:
@@ -150,9 +142,11 @@ def main():
     out = serve("qwen3-4b", smoke=True, cfg=cfg,
                 options=ServeOptions(n_requests=6, batch_slots=3,
                                      gen_len=48, max_len=64))
+    dev = jax.devices()[0]
     print(f"[serve_topk] completed {len(out['outputs'])} requests, "
           f"{out['tokens_generated']} tokens in {out['steps']} decode steps "
-          f"({out['tok_per_s']:.1f} tok/s on CPU, mean request latency "
+          f"({out['tok_per_s']:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, mean request latency "
           f"{out['latency_mean_s'] * 1e3:.1f} ms)")
     f = out["decode_fetch"]
     # kernel-side accounting: at sata_decode_replan=1 the exact
@@ -226,7 +220,7 @@ def _overload_schedule(seed):
             .corrupt_page(9).defer_admission(10))
 
 
-def overload_demo(cfg, seed, child_args, ckpt_dir=None, kill_at=None):
+def overload_demo(cfg, seed):
     """Overload resilience, three pillars asserted end to end:
 
     1. The QoS ladder turns a load-spike schedule that forces >=2
@@ -236,8 +230,8 @@ def overload_demo(cfg, seed, child_args, ckpt_dir=None, kill_at=None):
     2. A byte flipped in a parked swap payload is detected at the
        swap-in checksum gate and quarantined; the victim recovers by
        deterministic re-prefill (outputs unchanged).
-    3. A child process killed mid-serve resumes from its checkpoint in
-       this process with bitwise-equal outputs."""
+    3. A run killed mid-serve (``ServeKilled``) resumes from its
+       checkpoint with bitwise-equal outputs."""
     cfg = _with_decode(cfg, replan=4)
     cfg = dataclasses.replace(
         cfg,
@@ -246,19 +240,6 @@ def overload_demo(cfg, seed, child_args, ckpt_dir=None, kill_at=None):
     opt = ServeOptions(n_requests=4, batch_slots=2, gen_len=12,
                        max_len=32, prompt_len=6)
     faults = _overload_schedule(seed)
-    if ckpt_dir is not None:
-        # --- child mode: serve into the checkpoint dir until the
-        # injected kill, then die (the parent resumes from disk)
-        try:
-            serve("qwen3-4b", cfg=cfg, faults=faults, smoke=True,
-                  options=opt,
-                  resilience=ResilienceOptions(checkpoint_dir=ckpt_dir,
-                                               checkpoint_every=5,
-                                               kill_at_step=kill_at))
-        except ServeKilled as e:
-            print(f"[serve_topk] child: {e}")
-            return
-        raise AssertionError("child completed — kill step never reached")
     print(f"[serve_topk] overload schedule (seed {seed}):")
     print(faults.describe())
     base = serve("qwen3-4b", cfg=cfg, smoke=True, options=opt)  # no faults
@@ -298,19 +279,26 @@ def overload_demo(cfg, seed, child_args, ckpt_dir=None, kill_at=None):
     assert o["corrupt_pages_injected"] == 1
     assert o["corrupt_pages_detected"] == 1
     assert o["re_prefill_tokens"] > 0, "victim must recover by re-prefill"
-    # pillar 3 — cross-process kill/resume, bitwise
+    # pillar 3 — kill mid-serve, resume from the checkpoint, bitwise.
+    # Both runs stay in this process: a second process would contend
+    # for the accelerator this one already holds.
     d = tempfile.mkdtemp(prefix="serve_overload_ckpt_")
-    cmd = [sys.executable, os.path.abspath(__file__),
-           "--overload", str(seed), "--_ckpt-dir", d,
-           "--_kill-at", "13"] + child_args
-    subprocess.run(cmd, check=True, env=dict(os.environ))
+    try:
+        serve("qwen3-4b", cfg=cfg, faults=faults, smoke=True, options=opt,
+              resilience=ResilienceOptions(checkpoint_dir=d,
+                                           checkpoint_every=5,
+                                           kill_at_step=13))
+    except ServeKilled as e:
+        print(f"[serve_topk] {e}")
+    else:
+        raise AssertionError("run completed — kill step never reached")
     res = serve("qwen3-4b", cfg=cfg, faults=faults, smoke=True,
                 options=opt,
                 resilience=ResilienceOptions(checkpoint_dir=d,
                                              checkpoint_every=5,
                                              resume=True))
     equal = res["outputs"] == out["outputs"]
-    print(f"[serve_topk] killed child resumed at step "
+    print(f"[serve_topk] killed run resumed at step "
           f"{res['checkpoint']['resumed_at']}; outputs bitwise equal to "
           f"uninterrupted overload run: {equal}")
     assert equal, "checkpoint/resume changed outputs"

@@ -10,7 +10,10 @@ Run:  PYTHONPATH=src python examples/train_lm.py --steps 300
 (CPU-sized: ~1-2 s/step at batch 8 × seq 128.)
 """
 import argparse
+import os
+import tempfile
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import train
 from repro.models.config import ModelConfig
 import repro.launch.train as T
@@ -31,8 +34,10 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--dense", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/sata_lm100m")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "sata_lm100m"))
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = lm100m(args.dense)
     print(f"[train_lm] {cfg.name}: {cfg.param_count()/1e6:.0f}M params, "

@@ -176,8 +176,28 @@ def bisect_select(scores: jax.Array, threshold: jax.Array) -> jax.Array:
     reduction, the threshold-mode kernel, the chunked differentiation
     rule) MUST call this one helper: a drifted reimplementation would
     let the occupancy map and the kernel disagree about which tiles
-    hold work, silently dropping selected keys."""
-    return scores.astype(jnp.bfloat16) >= threshold.astype(jnp.bfloat16)
+    hold work, silently dropping selected keys.
+
+    Both sides round to bf16 (``bf16_round``) and compare in fp32: v5e
+    has no bf16 VPU and Mosaic refuses a bf16 ``cmpf`` inside a kernel."""
+    return bf16_round(scores) >= bf16_round(threshold)
+
+
+def bf16_round(x: jax.Array) -> jax.Array:
+    """``x`` rounded to the nearest bf16 (ties to even), kept in fp32.
+
+    Rounded on the bits, so XLA and a Mosaic kernel round alike.  With
+    a float convert round trip in its place, a jitted XLA program on a
+    v5e selected other keys than the same predicate inside a kernel,
+    and the selection predicate must agree bit for bit on both sides.
+    ``lax.reduce_precision`` would say this in one call, but the Pallas
+    TPU lowering (jax 0.9.0) has no rule for it.  Equal to
+    ``x.astype(bfloat16).astype(float32)`` for every non-NaN value on
+    the CPU."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    lsb = jax.lax.shift_right_logical(bits, jnp.uint32(16)) & jnp.uint32(1)
+    bits = (bits + jnp.uint32(0x7FFF) + lsb) & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def occupancy_from_score_chunk(scores_chunk: jax.Array, thr_chunk: jax.Array,

@@ -47,7 +47,7 @@ occupied k-block indices (``kv_indices (BH, nqb, P)``) plus a count
 (``kv_counts (BH, nqb)``).  Both ride in as *scalar prefetch* operands
 (``pltpu.PrefetchScalarGridSpec``), available to the BlockSpec index
 maps **before** the kernel body runs, so the K/V (and exact-mode mask)
-index maps dereference ``kv_indices[b, i, j]`` and the DMA engine only
+index maps dereference slot ``(b, i, j)`` of ``kv_indices`` and the DMA engine only
 ever fetches occupied tiles.  The grid shrinks to ``(BH, nqb, P)`` where
 ``P`` is the padded max occupancy — work scheduled, fetched, and
 computed all scale with the occupied-tile count, not ``nqb·nkb``.
@@ -95,13 +95,13 @@ def _acc_finalize(o_ref, acc_ref, l_ref):
 def _kernel(bm_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
             acc_ref, m_ref, l_ref, *, sm_scale: float, n_kb: int,
             exact: bool):
-    kj = pl.program_id(2)
+    b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
     def _init():
         _acc_init(acc_ref, m_ref, l_ref)
 
-    occupied = bm_ref[0, 0, 0] != 0
+    occupied = bm_ref[(b * pl.num_programs(1) + qi) * n_kb + kj] != 0
 
     @pl.when(occupied)
     def _update():
@@ -132,31 +132,39 @@ def sata_block_attention(
     if mask is None:
         mask = jnp.ones((bh, 1, 1), dtype=jnp.int8)    # dummy, never read
 
-    grid = (bh, nqb, nkb)
+    from jax.experimental.pallas import tpu as pltpu
+
     kernel = functools.partial(_kernel, sm_scale=sm_scale, n_kb=nkb,
                                exact=exact)
+    # index maps receive (grid ids..., block-map ref): the map rides in
+    # as a flattened scalar-prefetch operand (SMEM) — a (1, 1, 1) VMEM
+    # block over (BH, nqb, nkb) breaks Mosaic's (8, 128) tiling rule
     mask_spec = (pl.BlockSpec((1, q_block, k_block),
-                              lambda b, i, j: (b, i, j)) if exact
-                 else pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0)))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+                              lambda b, i, j, bm: (b, i, j)) if exact
+                 else pl.BlockSpec((1, 1, 1), lambda b, i, j, bm: (b, 0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(bh, nqb, nkb),
         in_specs=[
-            pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, i, j)),      # map
-            pl.BlockSpec((1, q_block, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, k_block, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, k_block, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, q_block, d), lambda b, i, j, bm: (b, i, 0)),
+            pl.BlockSpec((1, k_block, d), lambda b, i, j, bm: (b, j, 0)),
+            pl.BlockSpec((1, k_block, d), lambda b, i, j, bm: (b, j, 0)),
             mask_spec,
         ],
-        out_specs=pl.BlockSpec((1, q_block, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, q_block, d),
+                               lambda b, i, j, bm: (b, i, 0)),
         scratch_shapes=[
             _vmem((q_block, d), jnp.float32),       # acc
             _vmem((q_block, 1), jnp.float32),       # running max m
             _vmem((q_block, 1), jnp.float32),       # running sum l
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
-    )(block_map.astype(jnp.int32), q, k, v, mask)
+    )(block_map.reshape(-1).astype(jnp.int32), q, k, v, mask)
 
 
 def _vmem(shape, dtype):
@@ -229,7 +237,7 @@ def _compact_kernel(idx_ref, cnt_ref, q_ref, k_ref, v_ref, mask_ref,
     # Slots past the row's occupancy count are padding: their index maps
     # re-reference an already-resident tile (no fetch) and the body is
     # skipped entirely (no compute).
-    @pl.when(j < cnt_ref[b, qi])
+    @pl.when(j < cnt_ref[b * pl.num_programs(1) + qi])
     def _update():
         threshold = admissible = tile_mask = None
         if causal and select != "mask":
@@ -324,9 +332,15 @@ def sata_block_attention_compact(
     if thresholds.shape != (1, 1, 1):
         assert thresholds.shape == (bh, sq, 1), thresholds.shape
 
-    # index maps receive (grid ids..., *scalar-prefetch refs)
+    # index maps receive (grid ids..., *scalar-prefetch refs).  The plan
+    # rides in flattened to 1-D: SMEM pads a 3-D scalar array's minor
+    # dim to 128 words, which at serving widths (BH·nqb rows) overflows
+    # the 1 MiB scalar memory.
+    def _slot(b, i, j):
+        return (b * nqb + i) * n_slots + j
+
     def kv_map(b, i, j, idx_ref, cnt_ref):
-        return (b, idx_ref[b, i, j], 0)
+        return (b, idx_ref[_slot(b, i, j)], 0)
 
     def q_row_map(b, i, j, idx_ref, cnt_ref):
         return (b, i, 0)
@@ -338,7 +352,7 @@ def sata_block_attention_compact(
     mask_spec = (
         pl.BlockSpec((1, q_block, k_block),
                      lambda b, i, j, idx_ref, cnt_ref:
-                     (b, i, idx_ref[b, i, j])) if select == "mask"
+                     (b, i, idx_ref[_slot(b, i, j)])) if select == "mask"
         else dummy_spec)
     thr_spec = (pl.BlockSpec((1, q_block, 1), q_row_map)
                 if select == "threshold" else dummy_spec)
@@ -374,6 +388,7 @@ def sata_block_attention_compact(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
-    )(kv_indices.astype(jnp.int32), kv_counts.astype(jnp.int32),
+    )(kv_indices.reshape(-1).astype(jnp.int32),
+      kv_counts.reshape(-1).astype(jnp.int32),
       q, k, v, mask, thresholds.astype(jnp.float32),
       q_pos.astype(jnp.int32), k_pos.astype(jnp.int32))

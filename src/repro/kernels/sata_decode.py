@@ -9,7 +9,9 @@ a grid of ``(B·KV, P)`` — one slot per *selected* k-block, exactly the
 incremental plan (``core/decode_plan.py``) maintains.
 
 Scalar-prefetch operands (available to the BlockSpec index maps before
-the body runs, so the DMA engine only ever touches planned tiles):
+the body runs, so the DMA engine only ever touches planned tiles; each
+rides in flattened to 1-D, since SMEM pads a 2-D scalar array's minor
+dim to 128 words):
 
   kv_indices (B·KV, P) int32 — ascending selected k-block indices
                               (``compact_kv_plan`` padding: slots past
@@ -21,9 +23,14 @@ the body runs, so the DMA engine only ever touches planned tiles):
                               ragged slot lengths and freshly-claimed
                               (reset) slots never read stale cache.
 
-K/V stay in the serving cache layout ``(B, S, KV, D)`` — the index maps
-slice ``(b, block, kv_head)`` tiles directly, so no head-expanded or
-transposed copy of the cache is ever materialized.
+K/V stay in the serving cache layout ``(B, S, KV, D)``: the kernel views
+them as ``(B, S, KV·D)`` (a free reshape) and the index maps slice a
+``(k_block, D)`` tile whose column-block index is the kv head, so no
+head-expanded or transposed copy of the cache is ever materialized.
+The view keeps the block's last two dims at ``(k_block, D)`` — a
+``(k_block, 1, D)`` block over the 4-D cache would put a 1 against
+``KV`` in the second-minor dim, which Mosaic's (8, 128) tiling rule
+refuses.  On the chip ``D`` must be a multiple of 128.
 
 **Paged cache** (``sata_decode_attention_paged_kernel``): the serving
 cache may instead live in a global page pool ``(n_pages, page, KV, D)``
@@ -77,12 +84,12 @@ def _decode_kernel(idx_ref, cnt_ref, pos_ref, q_ref, k_ref, v_ref,
     @pl.when(j < cnt_ref[i])
     def _update():
         q = q_ref[0, 0]                            # (G, D)
-        k = k_ref[0, :, 0, :]                      # (k_block, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]                               # (k_block, D)
+        v = v_ref[0]
         # global key positions of the resident tile gate validity: the
         # plan may include the partially-written tail block, and padded
         # slots of *shorter* ragged rows must not see future tokens.
-        kpos = idx_ref[i, j] * k_block + \
+        kpos = idx_ref[i * n_slots + j] * k_block + \
             jax.lax.broadcasted_iota(jnp.int32, (1, k_block), 1)
         admissible = kpos <= pos_ref[i // n_kv]              # (1, k_block)
         _flash_update_tile(q, k, v, acc_ref, m_ref, l_ref,
@@ -124,19 +131,18 @@ def sata_decode_attention_kernel(
         return (i // n_kv, i % n_kv, 0, 0)
 
     def kv_map(i, j, idx_ref, cnt_ref, pos_ref):
-        return (i // n_kv, idx_ref[i, j], i % n_kv, 0)
-
-    def thr_map(i, j, idx_ref, cnt_ref, pos_ref):
-        return (i // n_kv, i % n_kv, 0, 0)
+        # K/V viewed as (B, S, KV·D): the block's column index IS the
+        # kv head, so a (k_block, D) tile is one head's rows
+        return (i // n_kv, idx_ref[i * p + j], i % n_kv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b * n_kv, p),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, k_block, 1, d), kv_map),
-            pl.BlockSpec((1, k_block, 1, d), kv_map),
-            pl.BlockSpec((1, 1, g, 1), thr_map),
+            pl.BlockSpec((1, k_block, d), kv_map),
+            pl.BlockSpec((1, k_block, d), kv_map),
+            pl.BlockSpec((1, 1, g, 1), q_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), q_map),
         scratch_shapes=[
@@ -152,10 +158,11 @@ def sata_decode_attention_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
         interpret=interpret,
-    )(kv_indices.reshape(b * n_kv, p).astype(jnp.int32),
-      kv_counts.reshape(b * n_kv).astype(jnp.int32),
+    )(kv_indices.reshape(-1).astype(jnp.int32),
+      kv_counts.reshape(-1).astype(jnp.int32),
       pos.astype(jnp.int32),
-      q, k, v, thresholds.astype(jnp.float32))
+      q, k.reshape(b, s, n_kv * d), v.reshape(b, s, n_kv * d),
+      thresholds.astype(jnp.float32))
 
 
 def _paged_decode_kernel(idx_ref, cnt_ref, pos_ref, tbl_ref, *args, **kw):
@@ -188,6 +195,7 @@ def sata_decode_attention_paged_kernel(
     assert kv_counts.shape == (b, n_kv), kv_counts.shape
     assert thresholds.shape == (b, n_kv, g, 1), thresholds.shape
     assert page_table.shape[0] == b, (page_table.shape, b)
+    max_pages = page_table.shape[1]
     assert pos.shape == (b,), pos.shape
     if p == 0:
         return jnp.zeros((b, n_kv, g, d), q.dtype)
@@ -199,15 +207,16 @@ def sata_decode_attention_paged_kernel(
     def kv_map(i, j, idx_ref, cnt_ref, pos_ref, tbl_ref):
         # the one paged-vs-contiguous difference: logical plan entry →
         # physical page through the slot's table row
-        return (tbl_ref[i // n_kv, idx_ref[i, j]], 0, i % n_kv, 0)
+        return (tbl_ref[(i // n_kv) * max_pages + idx_ref[i * p + j]],
+                0, i % n_kv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b * n_kv, p),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, page, 1, d), kv_map),
-            pl.BlockSpec((1, page, 1, d), kv_map),
+            pl.BlockSpec((1, page, d), kv_map),
+            pl.BlockSpec((1, page, d), kv_map),
             pl.BlockSpec((1, 1, g, 1), q_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), q_map),
@@ -224,8 +233,10 @@ def sata_decode_attention_paged_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
         interpret=interpret,
-    )(kv_indices.reshape(b * n_kv, p).astype(jnp.int32),
-      kv_counts.reshape(b * n_kv).astype(jnp.int32),
+    )(kv_indices.reshape(-1).astype(jnp.int32),
+      kv_counts.reshape(-1).astype(jnp.int32),
       pos.astype(jnp.int32),
-      page_table.astype(jnp.int32),
-      q, k_pages, v_pages, thresholds.astype(jnp.float32))
+      page_table.reshape(-1).astype(jnp.int32),
+      q, k_pages.reshape(n_pages, page, n_kv * d),
+      v_pages.reshape(n_pages, page, n_kv * d),
+      thresholds.astype(jnp.float32))

@@ -36,25 +36,18 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.core.blockmap import bisect_select, compact_kv_plan
 from repro.core.selection import NEG_INF, select_thresholds_chunked
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the explicit-axes API exists (jax>=0.5);
-    older jax (0.4.x) meshes are implicitly Auto."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod mesh: 16×16 (256 chips) single-pod, or 2×16×16 multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:
@@ -64,15 +57,14 @@ def data_axes(mesh) -> Tuple[str, ...]:
 
 def make_local_mesh():
     """Single-device mesh for CPU tests/examples."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def activate_mesh(mesh):
     """Context manager installing ``mesh`` for jit/sharding-constraint
-    resolution: ``jax.set_mesh`` where it exists (jax>=0.6), else the
-    classic ``Mesh.__enter__`` global-mesh context (jax 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    resolution."""
+    return jax.set_mesh(mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -226,12 +218,12 @@ def sequence_sharded_attention(mesh, q, k, v, *, k_sel: int,
         fetched = cnt.sum().reshape(1)
         return out, thr, bm, cnt, fetched
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, ax, None), P(ax),
-                             P(None, ax, None), P(None, ax, None)),
-                   out_specs=(P(None, ax, None), P(None, ax, None),
-                              P(None, ax, None), P(None, ax), P(ax)),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, ax, None), P(ax),
+                                 P(None, ax, None), P(None, ax, None)),
+                       out_specs=(P(None, ax, None), P(None, ax, None),
+                                  P(None, ax, None), P(None, ax), P(ax)),
+                       check_vma=False)
     q_pos = jnp.arange(s, dtype=jnp.int32)
     out, thr, bm, cnt, fetched = fn(q, q_pos, k, v)
     return out, {"thresholds": thr, "block_map": bm, "kv_counts": cnt,
@@ -324,10 +316,10 @@ def tensor_parallel_decode_step(mesh, qg, k, v, k_new, pos, plan, *,
         body = local_nt
     else:
         body = local
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, ax, None, None), cache_spec,
-                             cache_spec, cache_spec, P(None), pspec,
-                             tbl_spec),
-                   out_specs=(P(None, ax, None, None), pspec),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, ax, None, None), cache_spec,
+                                 cache_spec, cache_spec, P(None), pspec,
+                                 tbl_spec),
+                       out_specs=(P(None, ax, None, None), pspec),
+                       check_vma=False)
     return fn(qg, k, v, k_new, pos, plan, tbl_arg)

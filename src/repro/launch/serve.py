@@ -75,7 +75,7 @@ selection), so repeated-victim livelock is impossible.
 ``audit_pages`` (default on) runs ``PageAllocator.check_invariants``
 after every allocator mutation.
 
-Usage (CPU, reduced arch):
+Usage (reduced arch; ``--no-smoke`` serves the published widths):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke \
       --requests 8 --gen-len 16
 """
@@ -95,6 +95,7 @@ import numpy as np
 from repro.configs.archs import ARCHS, SMOKE
 from repro.core.paging import (PageAllocator, PageIntegrityError,
                                PrefixCache, SharedPrefixIndex)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.faults import FaultPlan
 from repro.launch.mesh import make_local_mesh
 from repro.models import attention as attn
@@ -395,7 +396,10 @@ def serve(arch: str, smoke: bool = True, *,
     cfg = cfg or (SMOKE if smoke else ARCHS)[arch]
     mesh = mesh or make_local_mesh()
     if params is None:
-        params = mdl.init_params(jax.random.PRNGKey(seed), cfg)
+        # one jitted program: op-by-op init would keep each layer
+        # stack's fp32 draw alive beside the cast params
+        params = jax.jit(lambda key: mdl.init_params(key, cfg))(
+            jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
 
     cache = dec.init_cache(cfg, batch_slots, max_len)
@@ -1563,7 +1567,10 @@ def serve_replicated(arch: str, *, n_replicas: int = 2,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen3-4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (--no-smoke: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--gen-len", type=int, default=16)
@@ -1584,6 +1591,7 @@ def main():
                     help="run N serve replicas around one shared prefix "
                          "index (implies --paged --prefix-cache)")
     args = ap.parse_args()
+    use_compile_cache()
     cfg = (SMOKE if args.smoke else ARCHS)[args.arch]
     if args.replicas:
         args.paged = args.prefix_cache = True
@@ -1619,9 +1627,11 @@ def main():
         return
     out = serve(args.arch, smoke=args.smoke, cfg=cfg, options=opts,
                 faults=faults, resilience=res)
+    dev = jax.devices()[0]
     print(f"[serve] generated {out['tokens_generated']} tokens over "
           f"{len(out['outputs'])} requests "
-          f"({out['tok_per_s']:.1f} tok/s on CPU, "
+          f"({out['tok_per_s']:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, "
           f"mean request latency {out['latency_mean_s'] * 1e3:.1f} ms)")
     if "decode_fetch" in out:
         f = out["decode_fetch"]
