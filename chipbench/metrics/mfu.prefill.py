@@ -1,0 +1,9 @@
+"""Model FLOP/s utilization of the prefill: the window's requests per
+second over whole jobs (host clock) times one prompt's prefill FLOPs
+(matmuls and causal attention, ``flops.prefill_flops``) over the chip's
+peak bf16 FLOP/s."""
+import flops
+
+
+def read(run):
+    return flops.prefill_mfu(run)

@@ -1,0 +1,6 @@
+"""Prompt tokens prefilled in all window jobs over their summed wall
+time (host clock; each job timed from ``serve()`` call to return)."""
+
+
+def read(run):
+    return run.rate("prompt_tokens")
