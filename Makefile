@@ -5,7 +5,7 @@ export PYTHONPATH
 
 .PHONY: test test-mesh dev-deps bench bench-select bench-decode \
 	serve-smoke serve-smoke-faults serve-smoke-overload \
-	serve-smoke-mesh roofline-kernel check-regression
+	serve-smoke-mesh check-regression
 
 dev-deps:
 	-pip install -r requirements-dev.txt
@@ -83,9 +83,6 @@ serve-smoke-overload:
 serve-smoke-mesh:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 		python examples/serve_topk.py --replicas 2
-
-roofline-kernel:
-	python -m repro.launch.roofline --kernel
 
 # Bench-regression gate (the CI step behind `make bench*`): regenerate
 # the three artifacts into results/bench_fresh and compare against the

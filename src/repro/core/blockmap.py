@@ -369,7 +369,7 @@ def block_skip_fraction(block_map: jax.Array) -> jax.Array:
 def fixed_occupancy_map(rng, bh: int, nqb: int, nkb: int, occ: int):
     """Host-side (numpy) random block map with exactly ``occ`` occupied
     k-blocks per (bh, q_row) — the concentrated regime SATA's key sort
-    produces, and the shape benchmarks/roofline use so the padded compact
+    produces, and the shape the benchmarks use so the padded compact
     grid (`P = occ`) actually shrinks (a Bernoulli map almost surely has
     one fully-occupied row pinning P at ``nkb``)."""
     bm = np.zeros((bh, nqb, nkb), dtype=bool)

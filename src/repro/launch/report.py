@@ -1,12 +1,11 @@
-"""Generate EXPERIMENTS.md tables from results/{dryrun,roofline}/*.json.
+"""Generate the EXPERIMENTS.md dry-run table from results/dryrun/*.json.
 
-Usage:  PYTHONPATH=src python -m repro.launch.report [dryrun|roofline]
+Usage:  PYTHONPATH=src python -m repro.launch.report
 """
 from __future__ import annotations
 
 import json
 import pathlib
-import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[3] / "results"
 
@@ -37,37 +36,10 @@ def dryrun_table() -> str:
     for r in rows:
         out.append("| " + " | ".join(str(x) for x in r) + " |")
     out.append("")
-    out.append("\\* while-loop bodies counted once by XLA — see §Roofline "
-               "for loop-corrected totals.")
-    return "\n".join(out)
-
-
-def roofline_table() -> str:
-    rows = []
-    for p in sorted((ROOT / "roofline").glob("*.json")):
-        r = json.loads(p.read_text())
-        t = r["terms_s"]
-        rows.append((r["cell"].replace("__pod1", ""),
-                     f"{t['compute_s']*1e3:.1f}",
-                     f"{t['memory_s']*1e3:.1f}",
-                     f"{t['collective_s']*1e3:.1f}",
-                     r["dominant"],
-                     f"{r['useful_ratio']:.2f}",
-                     f"{r['roofline_fraction']:.3f}",
-                     r["note"][:60] + "…"))
-    out = ["| arch × shape | compute ms | memory ms | collective ms | "
-           "bound | 6ND/HLO | roofline frac | to improve |",
-           "|---|---|---|---|---|---|---|---|"]
-    for r in rows:
-        out.append("| " + " | ".join(r) + " |")
+    out.append("\\* while-loop bodies counted once by XLA.")
     return "\n".join(out)
 
 
 if __name__ == "__main__":
-    which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    if which in ("dryrun", "both"):
-        print("### Dry-run\n")
-        print(dryrun_table())
-    if which in ("roofline", "both"):
-        print("\n### Roofline\n")
-        print(roofline_table())
+    print("### Dry-run\n")
+    print(dryrun_table())

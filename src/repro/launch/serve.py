@@ -98,6 +98,7 @@ from repro.core.paging import (PageAllocator, PageIntegrityError,
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.faults import FaultPlan
 from repro.launch.mesh import make_local_mesh
+from repro.launch.spans import Spans
 from repro.models import attention as attn
 from repro.models import decode as dec
 from repro.models import model as mdl
@@ -381,7 +382,21 @@ def serve(arch: str, smoke: bool = True, *,
     ``kill_at_step`` raises :class:`ServeKilled` right after the
     checkpoint block, and a fresh process calling with ``resume=True``
     replays from the last save — outputs bitwise equal to an
-    uninterrupted run."""
+    uninterrupted run.
+
+    Host spans (``launch/spans.py``): the report's ``spans`` holds the
+    totals of ``serve.setup`` (entry to the first loop iteration),
+    ``serve.step`` (one loop iteration) and its children
+    ``serve.prefill`` (one claim), ``serve.tables`` (the step's page
+    mapping and table push), ``serve.decode`` (the step's dispatch and
+    argmax), ``serve.account`` (fetch accounting), ``serve.emit`` (the
+    per-slot output loop) and ``serve.pull`` (each device→host read of
+    the loop, inside the others).  ``pulls`` counts those reads;
+    ``queue_wait_s`` is each request's wait from the call to its first
+    claim."""
+    t_call = time.time()
+    spans = Spans()
+    setup_span = spans("serve.setup").open()
     opt, res = _fold_serve_legacy(options, resilience, legacy)
     n_requests, batch_slots = opt.n_requests, opt.batch_slots
     gen_len, max_len = opt.gen_len, opt.max_len
@@ -530,6 +545,8 @@ def serve(arch: str, smoke: bool = True, *,
     outputs: Dict[int, List[int]] = {}
     latency: Dict[int, float] = {}
     t_claim: Dict[int, float] = {}
+    queue_wait: Dict[int, float] = {}             # call → first claim
+    pulls = 0                        # device→host reads in loop steps
     slots: List[Optional[int]] = [None] * batch_slots
     pos_h = np.zeros(batch_slots, np.int32)       # per-slot positions
     tokens_h = np.zeros((batch_slots, 1), np.int32)
@@ -565,6 +582,12 @@ def serve(arch: str, smoke: bool = True, *,
     # --- cascade retirement state
     retire_events = pages_reclaimed = retired_tokens = 0
     retire_log: Dict[int, List] = {}      # request → [(step, pages_freed)]
+
+    def _pull():
+        """Span one device→host read of a loop step, and count it."""
+        nonlocal pulls
+        pulls += 1
+        return spans("serve.pull")
 
     def _clear_backoff() -> None:
         """Pool capacity (may have) grown — deferred claims re-check
@@ -792,7 +815,8 @@ def serve(arch: str, smoke: bool = True, *,
             if n_ret <= 0:
                 continue
             if imp is None:                  # one device pull per sweep
-                a = _plan_field(cache, "imp")
+                with _pull():
+                    a = _plan_field(cache, "imp")
                 imp = a.reshape(-1, *a.shape[-3:])     # (L, B, KV, nkb)
             score = imp[:, i].sum(axis=(0, 1))         # (nkb,)
             # coldest first; ties retire the OLDEST block (deterministic)
@@ -825,6 +849,7 @@ def serve(arch: str, smoke: bool = True, *,
     jax.block_until_ready(logits)
     last_rep = _plan_replans(cache)               # skip warm-up's re-plan
     rep_base = None if last_rep is None else last_rep.copy()
+    plan_on = last_rep is not None                # the cache holds a plan
 
     def _ctrs():
         """Counter snapshot for the checkpoint meta blob — restore
@@ -902,7 +927,9 @@ def serve(arch: str, smoke: bool = True, *,
     # restart, so budget extra lockstep steps beyond the contiguous-
     # layout worst case
     max_steps = 4 * (n_requests * gen_len + batch_slots + 1)
+    setup_span.close()
     while (queue or any(s is not None for s in slots)) and steps < max_steps:
+        step_span = spans.step("serve.step", steps).open()
         if (ckpt is not None and checkpoint_every > 0
                 and steps % checkpoint_every == 0 and steps != last_ckpt):
             meta = {
@@ -928,6 +955,7 @@ def serve(arch: str, smoke: bool = True, *,
                       meta_blob=pickle.dumps(meta))
             last_ckpt = steps
         if kill_at_step is not None and steps == kill_at_step:
+            step_span.close()
             raise ServeKilled(f"injected process kill at loop step {steps}")
         defer_now = False
         pressure_now = False
@@ -1145,6 +1173,7 @@ def serve(arch: str, smoke: bool = True, *,
                         pressure_now = True
                         break
             r = queue.pop(0)
+            claim_span = spans("serve.prefill", request=r).open()
             slots[i] = r
             admit_seq[r] = admit_clock
             admit_clock += 1
@@ -1158,6 +1187,7 @@ def serve(arch: str, smoke: bool = True, *,
                 re_prefill_tokens += prompt_len - m   # requeue redoes it
             outputs[r] = []
             t_claim[r] = time.time()          # claim → last token
+            queue_wait.setdefault(r, t_claim[r] - t_call)
             cache = dec.reset_slot(cfg, cache, i)
             if use_prefill:
                 if pcache is not None and r not in noted:
@@ -1243,7 +1273,8 @@ def serve(arch: str, smoke: bool = True, *,
                 pos_h[i] = prompt_len
                 # the prefill's last-position argmax IS the first
                 # generated token — record it, don't just feed it
-                first = int(jnp.argmax(lg0[0]))
+                with _pull():
+                    first = int(jnp.argmax(lg0[0]))
                 outputs[r].append(first)
                 produced += 1
                 tokens_h[i, 0] = first
@@ -1257,9 +1288,11 @@ def serve(arch: str, smoke: bool = True, *,
             else:
                 pos_h[i] = 0
                 tokens_h[i, 0] = int(prompts[r, 0])
+            claim_span.close()
         active = [i for i in range(batch_slots) if slots[i] is not None]
         stalled: List[int] = []
         if alloc is not None and active:
+            tables_span = spans("serve.tables").open()
             while True:
                 stalled = [i for i in active if slots[i] is not None
                            and not _step_writable(i)]
@@ -1286,6 +1319,7 @@ def serve(arch: str, smoke: bool = True, *,
             _push_tables()
             # preemption may have freed slots out of the stale list
             active = [i for i in range(batch_slots) if slots[i] is not None]
+            tables_span.close()
         if qosctl is not None:
             if stalled:
                 pressure_now = True               # organic pool pressure
@@ -1297,13 +1331,19 @@ def serve(arch: str, smoke: bool = True, *,
             if qos_dirty:
                 cache = dec.set_qos_knobs(cache, *qosctl.vectors())
                 qos_dirty = False
-        logits, cache = step(params, cache, jnp.asarray(tokens_h),
-                             jnp.asarray(pos_h))
-        nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
-        counts = _plan_counts(cache)
+        with spans("serve.decode"):
+            logits, cache = step(params, cache, jnp.asarray(tokens_h),
+                                 jnp.asarray(pos_h))
+            nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+        account_span = spans("serve.account").open()
+        counts = rep = None
+        if plan_on:
+            with _pull():
+                counts = _plan_counts(cache)
+            with _pull():
+                rep = _plan_replans(cache)
         live = [i for i in active if i not in stalled]
         frac = 0.0
-        rep = _plan_replans(cache)
         if rep is not None:
             # per-(layer, slot) delta, attributed to LIVE slots only —
             # released slots never fire, and an idle slot's counter
@@ -1351,6 +1391,8 @@ def serve(arch: str, smoke: bool = True, *,
             plan_bytes += st["plan_fetch_bytes_step"]
             kernel_bytes_plan += st["kv_fetch_bytes_plan"]
             kernel_bytes_dense += st["kv_fetch_bytes_dense"]
+        account_span.close()
+        emit_span = spans("serve.emit").open()
         now = time.time()
         for i in range(batch_slots):
             r = slots[i]
@@ -1361,7 +1403,8 @@ def serve(arch: str, smoke: bool = True, *,
             # pages forever just because it also stalls
             req_steps[r] = req_steps.get(r, 0) + 1
             if i not in stalled:                  # stalled: re-fed as-is
-                outputs[r].append(int(nxt[i]))
+                with _pull():
+                    outputs[r].append(int(nxt[i]))
                 produced += 1
                 pos_h[i] += 1
             done = len(outputs[r]) >= gen_len or pos_h[i] >= max_len
@@ -1379,7 +1422,9 @@ def serve(arch: str, smoke: bool = True, *,
                     alloc.free_slot(i)            # … and its pages
                     _clear_backoff()
             elif i not in stalled:
-                tokens_h[i, 0] = int(nxt[i])
+                with _pull():
+                    tokens_h[i, 0] = int(nxt[i])
+        emit_span.close()
         if retire_on:
             # after the step: this step's selection is already folded
             # into the importance accumulator, and completed slots have
@@ -1390,10 +1435,13 @@ def serve(arch: str, smoke: bool = True, *,
                 _push_tables()
                 _clear_backoff()              # freed pages: re-check now
         steps += 1
+        step_span.close()
     dt = time.time() - t0
     out: Dict[str, Any] = {
         "outputs": outputs, "tokens_generated": produced,
         "tok_per_s": produced / max(dt, 1e-9), "steps": steps,
+        "pulls": pulls, "spans": spans.report(),
+        "queue_wait_s": queue_wait,
         "request_latency_s": latency,
         "latency_mean_s": float(np.mean(list(latency.values())))
         if latency else 0.0,
@@ -1633,6 +1681,16 @@ def main():
           f"({out['tok_per_s']:.1f} tok/s on {dev.platform} "
           f"{dev.device_kind}, "
           f"mean request latency {out['latency_mean_s'] * 1e3:.1f} ms)")
+    sp = out["spans"]
+
+    def secs(name):
+        return sp.get(name, {}).get("total_s", 0.0)
+    loop = secs("serve.step")
+    print(f"[serve] host time: set-up {secs('serve.setup'):.3f} s, "
+          f"{out['steps']} loop steps {loop:.3f} s (prefill "
+          f"{secs('serve.prefill'):.3f} s, decode steps "
+          f"{loop - secs('serve.prefill'):.3f} s; device reads "
+          f"{secs('serve.pull'):.3f} s over {out['pulls']} pulls)")
     if "decode_fetch" in out:
         f = out["decode_fetch"]
         print(f"[serve] SATA decode attention-kernel KV fetch: "

@@ -146,8 +146,8 @@ def _remat(cfg, fn):
 
 def maybe_scan(cfg, f, carry, xs):
     """lax.scan, or an unrolled Python loop when ``cfg.scan_layers`` is
-    False (used by the roofline probes: XLA cost analysis counts a while
-    body once regardless of trip count, so probes must unroll)."""
+    False (XLA cost analysis counts a while body once regardless of trip
+    count, so a cost probe must unroll)."""
     if cfg.scan_layers:
         return jax.lax.scan(f, carry, xs)
     n = jax.tree_util.tree_leaves(xs)[0].shape[0]
