@@ -201,30 +201,32 @@ class QoSController:
         return changed
 
 
-def _plan_field(cache: Dict, field: str) -> Optional[np.ndarray]:
-    """One field of the SATA decode-plan state, if routing is on
-    (hybrid keeps its attention cache under ``shared_kv``)."""
+def _plan_field(cache: Dict, field: str) -> Optional[jax.Array]:
+    """One field of the SATA decode-plan state, on the device, if
+    routing is on (hybrid keeps its attention cache under
+    ``shared_kv``)."""
     for name in ("kv", "shared_kv"):
         kvc = cache.get(name)
         if isinstance(kvc, dict) and "plan" in kvc:
-            return np.asarray(kvc["plan"][field])
+            return kvc["plan"][field]
     return None
 
 
-def _plan_counts(cache: Dict) -> Optional[np.ndarray]:
-    """Layer-stacked (L, B, KV) plan occupancy."""
-    cnt = _plan_field(cache, "kv_counts")
+def _plan_counts(cnt: Optional[np.ndarray]) -> Optional[np.ndarray]:
+    """Layer-stacked (L, B, KV) plan occupancy from the host copy of
+    the plan's ``kv_counts``."""
     return None if cnt is None else cnt.reshape(-1, *cnt.shape[-2:])
 
 
-def _plan_replans(cache: Dict) -> Optional[np.ndarray]:
-    """Cumulative per-(layer, slot) full-re-plan counters, layer axes
-    flattened to (L, B) — both the churn-adaptive trigger and the
-    per-slot beat fire independently, so the caller attributes deltas
-    to the slots that actually hold live requests."""
-    r = _plan_field(cache, "replans")
+def _plan_replans(r: Optional[Union[jax.Array, np.ndarray]]
+                  ) -> Optional[np.ndarray]:
+    """Cumulative per-(layer, slot) full-re-plan counters from the
+    plan's ``replans``, layer axes flattened to (L, B) — both the
+    churn-adaptive trigger and the per-slot beat fire independently, so
+    the caller attributes deltas to the slots that actually hold live
+    requests."""
     return None if r is None else \
-        r.astype(np.float64).reshape(-1, r.shape[-1])
+        np.asarray(r, np.float64).reshape(-1, r.shape[-1])
 
 
 def _pick_victim(stalled: List[int], slots: List[Optional[int]],
@@ -389,9 +391,12 @@ def serve(arch: str, smoke: bool = True, *,
     ``serve.step`` (one loop iteration) and its children
     ``serve.prefill`` (one claim), ``serve.tables`` (the step's page
     mapping and table push), ``serve.decode`` (the step's dispatch and
-    argmax), ``serve.account`` (fetch accounting), ``serve.emit`` (the
-    per-slot output loop) and ``serve.pull`` (each device→host read of
-    the loop, inside the others).  ``pulls`` counts those reads;
+    argmax), ``serve.account`` (the step's read and fetch accounting),
+    ``serve.emit`` (the per-slot output loop) and ``serve.pull`` (each
+    device→host read of the loop, inside the others: one a step — its
+    tokens and plan counters in one transfer — plus each claim's first
+    token and each retirement sweep's importance read).  ``pulls``
+    counts those reads;
     ``queue_wait_s`` is each request's wait from the call to its first
     claim."""
     t_call = time.time()
@@ -739,7 +744,7 @@ def serve(arch: str, smoke: bool = True, *,
         # cache's counters restart the delta accounting
         if last_rep is not None:
             rep_offset += float((last_rep - rep_base).mean())
-            last_rep = _plan_replans(cache)
+            last_rep = _plan_replans(_plan_field(cache, "replans"))
             rep_base = last_rep.copy()
         crashes += 1
     from repro.kernels.ops import decode_fetch_stats
@@ -816,7 +821,7 @@ def serve(arch: str, smoke: bool = True, *,
                 continue
             if imp is None:                  # one device pull per sweep
                 with _pull():
-                    a = _plan_field(cache, "imp")
+                    a = np.asarray(_plan_field(cache, "imp"))
                 imp = a.reshape(-1, *a.shape[-3:])     # (L, B, KV, nkb)
             score = imp[:, i].sum(axis=(0, 1))         # (nkb,)
             # coldest first; ties retire the OLDEST block (deterministic)
@@ -847,9 +852,9 @@ def serve(arch: str, smoke: bool = True, *,
     logits, cache = step(params, cache, jnp.asarray(tokens_h),
                          jnp.asarray(pos_h))
     jax.block_until_ready(logits)
-    last_rep = _plan_replans(cache)               # skip warm-up's re-plan
+    # the baseline skips warm-up's re-plan
+    last_rep = _plan_replans(_plan_field(cache, "replans"))
     rep_base = None if last_rep is None else last_rep.copy()
-    plan_on = last_rep is not None                # the cache holds a plan
 
     def _ctrs():
         """Counter snapshot for the checkpoint meta blob — restore
@@ -1336,12 +1341,13 @@ def serve(arch: str, smoke: bool = True, *,
                                  jnp.asarray(pos_h))
             nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
         account_span = spans("serve.account").open()
-        counts = rep = None
-        if plan_on:
-            with _pull():
-                counts = _plan_counts(cache)
-            with _pull():
-                rep = _plan_replans(cache)
+        # the step's one device→host read: its tokens and, with a plan,
+        # the plan's occupancy and re-plan counters, in one transfer
+        with _pull():
+            nxt_h, cnt, rep = jax.device_get(
+                (nxt, _plan_field(cache, "kv_counts"),
+                 _plan_field(cache, "replans")))
+        counts, rep = _plan_counts(cnt), _plan_replans(rep)
         live = [i for i in active if i not in stalled]
         frac = 0.0
         if rep is not None:
@@ -1403,8 +1409,7 @@ def serve(arch: str, smoke: bool = True, *,
             # pages forever just because it also stalls
             req_steps[r] = req_steps.get(r, 0) + 1
             if i not in stalled:                  # stalled: re-fed as-is
-                with _pull():
-                    outputs[r].append(int(nxt[i]))
+                outputs[r].append(int(nxt_h[i]))
                 produced += 1
                 pos_h[i] += 1
             done = len(outputs[r]) >= gen_len or pos_h[i] >= max_len
@@ -1422,8 +1427,7 @@ def serve(arch: str, smoke: bool = True, *,
                     alloc.free_slot(i)            # … and its pages
                     _clear_backoff()
             elif i not in stalled:
-                with _pull():
-                    tokens_h[i, 0] = int(nxt[i])
+                tokens_h[i, 0] = nxt_h[i]
         emit_span.close()
         if retire_on:
             # after the step: this step's selection is already folded
@@ -1457,7 +1461,7 @@ def serve(arch: str, smoke: bool = True, *,
         # importance (the decayed accumulator summed over layers, slots
         # and blocks) — the signal a future head-pruning cascade would
         # rank on, surfaced with zero behavior change
-        a = _plan_field(cache, "imp")
+        a = np.asarray(_plan_field(cache, "imp"))
         head_imp = a.reshape(-1, *a.shape[-3:]).sum(axis=(0, 1, 3))
         out["retirement"] = {
             "events": retire_events,

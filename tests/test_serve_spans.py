@@ -64,11 +64,9 @@ def test_report_spans_and_counters(traced):
     # the children of a step take no more than the steps themselves
     kids = sum(sp[n]["total_s"] for n in NAMES[2:7])
     assert kids <= sp["serve.step"]["total_s"]
-    # reads: each prefill's first token, two plan pulls a step, each
-    # token a decode step appends, each token fed back to the next step
-    appended = out["tokens_generated"] - N_REQ
-    fed_back = appended - N_REQ          # a request's last token is not
-    assert out["pulls"] == N_REQ + 2 * steps + appended + fed_back
+    # reads: each prefill's first token, then one a step (its tokens
+    # and plan counters in one transfer)
+    assert out["pulls"] == N_REQ + steps
     assert sp["serve.pull"]["count"] == out["pulls"]
     # queue wait: one entry a request, non-negative, in claim order
     qw = out["queue_wait_s"]
@@ -114,9 +112,12 @@ def test_profiler_host_plane(traced):
     assert all(s[1] >= setup[0][2] for s in steps)
     assert all(a[2] <= b[1] for a, b in zip(steps, steps[1:]))
     assert len(pulls) == out["pulls"]
+    claims = [e for e in evs if e[0] == "serve.prefill"]
     for s in steps:
-        inside = [p for p in pulls if s[1] <= p[1] and p[2] <= s[2]]
-        assert len(inside) >= 2                  # the plan pulls
+        inside = [p for p in pulls if s[1] <= p[1] and p[2] <= s[2]
+                  and not any(c[1] <= p[1] and p[2] <= c[2]
+                              for c in claims)]
+        assert len(inside) == 1      # the step's read; claims read apart
     # every pull lies inside a step
     assert all(any(s[1] <= p[1] and p[2] <= s[2] for s in steps)
                for p in pulls)
