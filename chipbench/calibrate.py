@@ -8,8 +8,9 @@ For each seed, in one process on the chip: the weights from the seed,
 the first jobs a run with that seed would serve (as many as its check
 sample needs), and, over the requests such a run would check, the gap
 by which each served token's logit lies below the float32 reference's
-best (the program), and the same gap for the token each control of
-``reference.py`` puts first at that position (on the first
+best (the program), and the same gap for the token each control of the
+configuration's reference module (its ``"reference"``, e.g.
+``refs/dense.py``) puts first at that position (on the first
 ``--control-seeds`` seeds).  Of each: the widest gap (``max``), the
 mean, the 90th percentile and the share of tokens that are not the
 reference's best, with the first token's (the prefill's) widest gap
@@ -48,8 +49,8 @@ def main(argv=None) -> int:
     if run.chips(spec["chips"]) is None:
         return 2
     import numpy as np
-    import reference
     import weights
+    ref = spec["reference"]
     prog = run.Program(spec["config"], spec["traffic"])
     s = prog.shape
     model = {**spec["config"]["model"], **spec["config"]["program_fixed"]}
@@ -73,9 +74,9 @@ def main(argv=None) -> int:
                                 s.requests_per_job,
                                 s.requests_per_job * s.prompt_len,
                                 int(rep["tokens_generated"]), rep))
-        controls = reference.CONTROLS if i < n_ctl else ()
+        controls = ref.CONTROLS if i < n_ctl else ()
         t = time.perf_counter()
-        both = [run.token_gaps(prog, params, model, j, r, controls)
+        both = [run.token_gaps(ref, prog, params, model, j, r, controls)
                 for j, r in run.sample(jobs, n_check, seed)]
         row = {"seed": seed,
                "program": stats(np.stack([b[0] for b in both]))}
@@ -89,7 +90,7 @@ def main(argv=None) -> int:
     summary = {"workload": args.workload, "seeds": len(rows),
                "program_max": {k: max(r["program"][k] for r in rows)
                                for k in rows[0]["program"]}}
-    for c in reference.CONTROLS:
+    for c in ref.CONTROLS:
         got = [r[c] for r in rows if c in r]
         if got:
             summary[c + "_min"] = {k: min(g[k] for g in got) for k in got[0]}

@@ -1,53 +1,18 @@
-"""Operation and byte counts of the served models, from shapes alone.
+"""Operation and byte counts of the shared decode kernel, and the
+model-FLOP shares the readers report, from shapes alone.
 
 ``m`` is the ``model`` group of a configuration file
 (``configs/<name>.json``): the fields the program's ``ModelConfig``
-takes.  Nothing here reads the program.
-
-Attention is counted as the model defines it: each query scores every
-key it may see (``2·H·hd`` per key, which is what an exact top-k
-selection needs) and mixes the values of the ``topk_k`` keys it keeps
-(``2·H·hd`` per kept key).  Matmul FLOPs are ``2`` per weight.
+takes.  Nothing here reads the program.  A model's own count (FLOPs of
+a prefill and of a decode step) lives in the reference module its
+configuration names (``refs/dense.py`` and its interface), which a run
+carries as ``Run.ref``.
 """
 from __future__ import annotations
 
 
 def head_dim(m: dict) -> int:
     return int(m.get("head_dim") or m["d_model"] // m["n_heads"])
-
-
-def layer_matmul_params(m: dict) -> int:
-    """Weights of one decoder layer's matmuls (q, k, v, o and the MLP)."""
-    d, hd = m["d_model"], head_dim(m)
-    attn = 2 * d * m["n_heads"] * hd + 2 * d * m["n_kv_heads"] * hd
-    per_ff = 3 if m.get("mlp_variant", "swiglu") == "swiglu" else 2
-    return attn + per_ff * d * m["d_ff"]
-
-
-def unembed_params(m: dict) -> int:
-    return m["d_model"] * m["vocab_size"]
-
-
-def attn_flops(m: dict, ctx: int) -> int:
-    """One query position over ``ctx`` visible keys, all layers."""
-    per_key = 2 * m["n_heads"] * head_dim(m)
-    kept = min(int(m.get("topk_k") or ctx), ctx)
-    return m["n_layers"] * per_key * (ctx + kept)
-
-
-def prefill_flops(m: dict, prompt_len: int) -> int:
-    """A causal prefill of ``prompt_len`` tokens that unembeds only the
-    last position (the first generated token's logits)."""
-    body = 2 * m["n_layers"] * layer_matmul_params(m) * prompt_len
-    attn = sum(attn_flops(m, i + 1) for i in range(prompt_len))
-    return body + attn + 2 * unembed_params(m)
-
-
-def decode_step_flops(m: dict, pos: int) -> int:
-    """One generated token written at position ``pos`` (``pos + 1``
-    visible keys), unembedded."""
-    return (2 * m["n_layers"] * layer_matmul_params(m)
-            + attn_flops(m, pos + 1) + 2 * unembed_params(m))
 
 
 def decode_kernel_call(m: dict, *, slots: int, tiles: float, block: int,
@@ -75,14 +40,15 @@ def decode_kernel_call(m: dict, *, slots: int, tiles: float, block: int,
 
 def decode_mfu(run, period_s: float):
     """Percent of the chip's peak FLOP/s that the decode steps carried:
-    one step's model FLOPs for every busy slot (``decode_step_flops``
-    averaged over the positions a request's generated tokens are
-    written at) over the step period ``period_s``."""
+    one step's model FLOPs for every busy slot (the run's reference
+    module's ``decode_step_flops``, averaged over the positions a
+    request's generated tokens are written at) over the step period
+    ``period_s``."""
     s = run.shape
     positions = range(s.prompt_len, s.prompt_len + s.gen_len - 1)
     if not positions or not period_s:
         return None
-    per_slot = sum(decode_step_flops(run.model, p)
+    per_slot = sum(run.ref.decode_step_flops(run.model, p)
                    for p in positions) / len(positions)
     slots = min(s.slots, s.requests_per_job)
     return 100.0 * slots * per_slot / period_s / run.peak["bf16_flops_per_s"]
@@ -91,9 +57,10 @@ def decode_mfu(run, period_s: float):
 def prefill_mfu(run):
     """Percent of the chip's peak FLOP/s that the window's prefills
     carried: requests per second over the whole jobs (host clock) times
-    one prompt's prefill FLOPs."""
+    one prompt's prefill FLOPs (the run's reference module's
+    ``prefill_flops``)."""
     rps = run.rate("requests")
     if rps is None:
         return None
-    f = prefill_flops(run.model, run.shape.prompt_len)
+    f = run.ref.prefill_flops(run.model, run.shape.prompt_len)
     return 100.0 * rps * f / run.peak["bf16_flops_per_s"]

@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of the prefill: the window's requests per
 second over whole jobs (host clock) times one prompt's prefill FLOPs
-(matmuls and causal attention, ``flops.prefill_flops``) over the chip's
-peak bf16 FLOP/s."""
+(matmuls and causal attention: ``prefill_flops`` of the configuration's
+reference module, ``Run.ref``) over the chip's peak bf16 FLOP/s."""
 import flops
 
 

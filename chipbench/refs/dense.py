@@ -1,8 +1,36 @@
-"""Plain float32 reference of the served decoder, and its fp8 controls.
+"""Plain float32 reference of the dense decoder, its fp8 controls and
+its operation count.
+
+A configuration names its reference module by a path under
+``chipbench/``: the ``"reference"`` key of ``configs/<name>.json``
+(``"refs/dense.py"`` for this one).  The harness loads the module by
+that path (``run.load_reference``) and uses nothing of it but these
+four names, the interface every reference module defines:
+
+* ``logits(params, model, tokens, out_pos, low="")``: float32 logits,
+  ``(len(out_pos), vocab)``, at positions ``out_pos`` of the token
+  sequence ``tokens``, from the benchmark's weights ``params``
+  (``weights.py``) and the configuration's ``model`` group merged with
+  its ``program_fixed`` (``model`` below); with ``low`` one of
+  ``CONTROLS``, that control's logits;
+* ``CONTROLS``: the names of the controls, each the reference computed
+  one precision below the configuration's;
+* ``prefill_flops(model, prompt_len)``: FLOPs of a causal prefill of
+  ``prompt_len`` tokens that unembeds its last position (``mfu.prefill``);
+* ``decode_step_flops(model, pos)``: FLOPs of one generated token
+  written at position ``pos`` (``mfu.decode``).
+
+The count follows the same equations as the logits, so that the two
+cannot drift apart.  A module refuses, at ``logits`` and at its counts,
+a ``model`` group it does not compute: here any key outside ``FIELDS``
+and ``name``, ``family``, ``dtype``, or a ``family`` other than
+``"dense"``.  The float32 and fp8 helpers ``_q8``, ``_norm``, ``_rope``
+and ``_attention`` are for other reference modules to import
+(``from refs.dense import _q8``) rather than copy.
 
 Imports nothing of the program.  It reads the weights the benchmark
-made (``weights.py``) in the layout the serving program takes them,
-and the model's math from the configuration file's ``model`` group:
+made in the layout the serving program takes them, and the model's
+math from the ``model`` group:
 
   x = E[tokens]
   per layer:  h = norm(x);  q, k, v = h·Wq, h·Wk, h·Wv
@@ -33,6 +61,11 @@ largest:
   attention-probability, attention-output and MLP result) is rounded to
   e4m3 instead; scores, softmax and accumulation stay float32, as in
   the program.
+
+Operations are counted as the model defines them: each query scores
+every key it may see (``2·H·hd`` per key, which is what an exact top-k
+selection needs) and mixes the values of the ``topk_k`` keys it keeps
+(``2·H·hd`` per kept key); a matmul is ``2`` FLOPs per weight.
 """
 from __future__ import annotations
 
@@ -41,6 +74,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from flops import head_dim
 
 
 def _q8(x, axis):
@@ -105,12 +140,27 @@ def _attention(q, k, v, topk, low):
     return _r(jnp.einsum("hqk,khd->qhd", p, v), low)
 
 
+FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
+          "vocab_size", "head_dim", "qk_norm", "rope_theta", "norm_type",
+          "norm_eps", "mlp_variant", "tie_embeddings", "topk_k",
+          "attention_variant")
+
+
+def _check(m: dict) -> None:
+    """Refuse a ``model`` group this module does not compute, so that a
+    configuration pointed here by mistake fails instead of being held
+    to the wrong model."""
+    extra = set(m) - set(FIELDS) - {"name", "family", "dtype"}
+    if extra:
+        raise ValueError(f"refs/dense.py does not compute model key(s) "
+                         f"{sorted(extra)}")
+    if m.get("family") != "dense":
+        raise ValueError(f"refs/dense.py computes family 'dense', not "
+                         f"{m.get('family')!r}")
+
+
 def _model_key(m):
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-            "vocab_size", "head_dim", "qk_norm", "rope_theta", "norm_type",
-            "norm_eps", "mlp_variant", "tie_embeddings", "topk_k",
-            "attention_variant")
-    return tuple((k, m.get(k)) for k in keys)
+    return tuple((k, m.get(k)) for k in FIELDS)
 
 
 @functools.partial(jax.jit, static_argnames=("mkey", "low"))
@@ -163,8 +213,45 @@ def logits(params, model: dict, tokens, out_pos, low: str = ""):
     """Reference logits (len(out_pos), vocab) in float32 at positions
     ``out_pos`` of the token sequence ``tokens``; with ``low`` one of
     ``CONTROLS``, that control's."""
+    _check(model)
     if low and low not in CONTROLS:
         raise ValueError(f"no control {low!r}; have {CONTROLS}")
     return _logits(params, jnp.asarray(tokens, jnp.int32),
                    jnp.asarray(out_pos, jnp.int32),
                    mkey=_model_key(model), low=low)
+
+
+def layer_matmul_params(m: dict) -> int:
+    """Weights of one decoder layer's matmuls (q, k, v, o and the MLP)."""
+    d, hd = m["d_model"], head_dim(m)
+    attn = 2 * d * m["n_heads"] * hd + 2 * d * m["n_kv_heads"] * hd
+    per_ff = 3 if m.get("mlp_variant", "swiglu") == "swiglu" else 2
+    return attn + per_ff * d * m["d_ff"]
+
+
+def unembed_params(m: dict) -> int:
+    return m["d_model"] * m["vocab_size"]
+
+
+def attn_flops(m: dict, ctx: int) -> int:
+    """One query position over ``ctx`` visible keys, all layers."""
+    per_key = 2 * m["n_heads"] * head_dim(m)
+    kept = min(int(m.get("topk_k") or ctx), ctx)
+    return m["n_layers"] * per_key * (ctx + kept)
+
+
+def prefill_flops(m: dict, prompt_len: int) -> int:
+    """A causal prefill of ``prompt_len`` tokens that unembeds only the
+    last position (the first generated token's logits)."""
+    _check(m)
+    body = 2 * m["n_layers"] * layer_matmul_params(m) * prompt_len
+    attn = sum(attn_flops(m, i + 1) for i in range(prompt_len))
+    return body + attn + 2 * unembed_params(m)
+
+
+def decode_step_flops(m: dict, pos: int) -> int:
+    """One generated token written at position ``pos`` (``pos + 1``
+    visible keys), unembedded."""
+    _check(m)
+    return (2 * m["n_layers"] * layer_matmul_params(m)
+            + attn_flops(m, pos + 1) + 2 * unembed_params(m))

@@ -7,8 +7,12 @@ The cell (``BENCHMARK.json``'s ``workloads`` entry) names a model
 configuration (``configs/<config>.json``), a traffic mix
 (``traffic/<traffic>.json``) and has a file of its own
 (``cells/<workload>.json``: trace window, correctness sample and
-limit).  Each metric is read by ``metrics/<metric>.py``.  Nothing here
-branches on a cell, configuration or metric name.
+limit).  The configuration names its reference module, a path under
+``chipbench/`` (``"reference": "refs/dense.py"``), which holds the
+model's float32 logits, its controls and its FLOP count (the interface
+is in ``refs/dense.py``).  Each metric is read by
+``metrics/<metric>.py``.  Nothing here branches on a cell,
+configuration or metric name, or imports a model by name.
 
 A run:
 
@@ -27,7 +31,8 @@ A run:
    first job (the cell file says which) and the per-layer metrics are
    read; with ``--trace 0`` the end-to-end ones;
 3. check: reads the device's peak memory, then runs the float32
-   reference (``reference.py``) over a sample of the finished requests,
+   reference that the configuration names (``refs/dense.py`` for the
+   dense decoders) over a sample of the finished requests,
    drawn from the seed, and compares every served token's logit with
    the reference's best at its position: the widest and the mean of
    those gaps are each held to a limit of the cell's.
@@ -96,9 +101,14 @@ def load_spec(workload: str, root: Path = ROOT) -> Dict[str, Any]:
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
+    config = json.loads((root / conf["file"]).read_text())
+    if "reference" not in config:
+        raise SystemExit(f"configuration {conf['file']} names no "
+                         f"\"reference\" module")
     return {
         "name": workload, "chips": int(wl["chips"]),
-        "config": json.loads((root / conf["file"]).read_text()),
+        "config": config,
+        "reference": load_reference(config["reference"], here),
         "traffic": json.loads(
             (here / "traffic" / f"{wl['traffic']}.json").read_text()),
         "cell": json.loads((here / "cells" / f"{workload}.json").read_text()),
@@ -106,14 +116,29 @@ def load_spec(workload: str, root: Path = ROOT) -> Dict[str, Any]:
     }
 
 
-def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
+def _load(path: Path, name: str):
+    """The Python file at ``path``, loaded as a module named ``name``
+    (its non-alphanumeric characters made ``_``)."""
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
-        path)
+        "".join(c if c.isalnum() else "_" for c in name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    return _load(HERE / "metrics" / f"{name}.py",
+                 "chipbench_metric_" + name).read
+
+
+def load_reference(rel: str, here: Path = HERE):
+    """The reference module at ``rel``, a path under ``chipbench/``
+    (``here``), as a configuration's ``"reference"`` names it."""
+    path = (here / rel).resolve()
+    if not path.is_relative_to(here.resolve()) or path.suffix != ".py":
+        raise SystemExit(f"reference {rel!r} is not a .py file under "
+                         f"chipbench/")
+    return _load(path, "chipbench_ref_" + rel[:-3])
 
 
 def peaks_for(kind: str) -> Dict[str, Any]:
@@ -227,6 +252,7 @@ class Run:
     setup_s: float
     jobs: List[Job]
     trace: Any = None                     # xtrace.TraceView when traced
+    ref: Any = None                       # the configuration's reference
 
     def rate(self, field: str) -> Optional[float]:
         wall = sum(j.wall_s for j in self.jobs)
@@ -287,26 +313,25 @@ def sample(jobs: List[Job], n: int, seed: int):
     return [pairs[int(i)] for i in sorted(pick)]
 
 
-def token_gaps(prog: Program, params, model, job: Job, r: int,
+def token_gaps(ref, prog: Program, params, model, job: Job, r: int,
                controls=()):
     """For each token served to request ``r`` of ``job``: the gap by
-    which its float32 reference logit lies below the reference's best
-    at its position; and for each control named in ``controls``
-    (``reference.CONTROLS``) the same gap for the token that control
-    puts first there.  Returns ``(gaps, {control: gaps})``."""
+    which its float32 logit in the reference module ``ref`` lies below
+    the reference's best at its position; and for each control named in
+    ``controls`` (``ref.CONTROLS``) the same gap for the token that
+    control puts first there.  Returns ``(gaps, {control: gaps})``."""
     import numpy as np
-    import reference
     served = np.asarray(job.report["outputs"][r])
     seq = np.concatenate([prog.prompts(job.seed, job.requests)[r],
                           served[:-1]])
     pos = np.arange(prog.shape.prompt_len - 1, len(seq))
-    ref = np.asarray(reference.logits(params, model, seq, pos), np.float64)
-    best, rows = ref.max(-1), np.arange(len(served))
+    exact = np.asarray(ref.logits(params, model, seq, pos), np.float64)
+    best, rows = exact.max(-1), np.arange(len(served))
     out = {}
     for c in controls:
-        low = np.asarray(reference.logits(params, model, seq, pos, low=c))
-        out[c] = best - ref[rows, low.argmax(-1)]
-    return best - ref[rows, served], out
+        low = np.asarray(ref.logits(params, model, seq, pos, low=c))
+        out[c] = best - exact[rows, low.argmax(-1)]
+    return best - exact[rows, served], out
 
 
 def gap_checks(gaps, failed: int, cell: Dict[str, Any]) -> Dict[str, Any]:
@@ -404,15 +429,16 @@ def _run_cell(spec, seed, seconds, trace, devices, peak, t0, compiles):
               for d in devices)
     say(f"peak_bytes_in_use: {mem} (cell file estimate: "
         f"{cell.get('memory_estimate_bytes')})")
+    ref = spec["reference"]
     run = Run(model, shape, peak, setup_s, jobs,
-              tracer.view() if tracer is not None else None)
+              tracer.view() if tracer is not None else None, ref)
 
     # --- check: every request finished, served tokens vs reference
     failed = sum(
         1 for j in jobs for r in range(j.requests)
         if len(j.report["outputs"].get(r, [])) != shape.gen_len
         or r in j.report.get("timed_out", []))
-    gaps = [token_gaps(prog, params, model, j, r)[0]
+    gaps = [token_gaps(ref, prog, params, model, j, r)[0]
             for j, r in sample(jobs, int(cell["check_requests"]), seed)
             if len(j.report["outputs"].get(r, [])) == shape.gen_len]
     checks = gap_checks(gaps, failed, cell)

@@ -6,7 +6,6 @@ import jax
 import pytest
 
 import faults
-import reference
 import run
 import tiny
 import weights
@@ -50,14 +49,15 @@ def test_control_is_caught(seed):
     prog = run.Program(spec["config"], spec["traffic"])
     s = prog.shape
     model = {**spec["config"]["model"], **spec["config"]["program_fixed"]}
+    ref = spec["reference"]
     params = weights.make(prog.param_shapes(), weights.seed_word(seed, 0))
     js = weights.seed_word(seed, 2, 0)
     rep = prog.job(params, js, s.requests_per_job, s.gen_len)
     job = run.Job(js, 1.0, s.requests_per_job, 0,
                   int(rep["tokens_generated"]), rep)
-    both = [run.token_gaps(prog, params, model, job, r, reference.CONTROLS)
+    both = [run.token_gaps(ref, prog, params, model, job, r, ref.CONTROLS)
             for _, r in run.sample([job], 4, seed)]
     cell = spec["cell"]
     assert not _failed(run.gap_checks([g for g, _ in both], 0, cell))
-    for c in reference.CONTROLS:
+    for c in ref.CONTROLS:
         assert _failed(run.gap_checks([x[c] for _, x in both], 0, cell)), c

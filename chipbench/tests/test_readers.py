@@ -29,7 +29,8 @@ def _run(trace=VIEW, wall=20.0):
     job = run.Job(1, wall, 16, 16 * 1536, 16 * 128, report)
     shape = run.Shape(page=128, **TRAFFIC)
     model = {**CONFIG["model"], **CONFIG["program_fixed"]}
-    return run.Run(model, shape, PEAK, 30.0, [job, job], trace)
+    return run.Run(model, shape, PEAK, 30.0, [job, job], trace,
+                   run.load_reference(CONFIG["reference"]))
 
 
 def test_rates():
@@ -54,19 +55,19 @@ def test_trace_readers():
 
 
 def test_mfu_below_peak():
-    import flops
+    from refs import dense
     r = _run()
     mfu = run.load_reader("mfu.decode")(r)
     assert 0 < mfu < 100
     # 16 busy slots, each one decode step per period; positions 1536-1662
-    per = sum(flops.decode_step_flops(r.model, p)
+    per = sum(dense.decode_step_flops(r.model, p)
               for p in range(1536, 1663)) / 127
     period = run.load_reader("decode_step_ms")(r) * 1e-3
     assert mfu == pytest.approx(16 * per / period
                                 / PEAK["bf16_flops_per_s"] * 100)
     # prefill: 32 requests over 40 s, one prompt's prefill each
     pre = run.load_reader("mfu.prefill")(r)
-    assert pre == pytest.approx(32 / 40 * flops.prefill_flops(r.model, 1536)
+    assert pre == pytest.approx(32 / 40 * dense.prefill_flops(r.model, 1536)
                                 / PEAK["bf16_flops_per_s"] * 100)
 
 

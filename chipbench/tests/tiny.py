@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import copy
 
+import run
+
 # float32, so the program's own rounding leaves the served tokens at
 # the reference's best (over seeds 1-5 widest gaps 0 to 0.0086, mean
 # gaps 0 to 0.00027) and both fp8 controls (widest 0.095 to 0.184, mean
@@ -20,6 +22,7 @@ MODEL = {
 SPEC = {
     "name": "tiny.decode", "chips": 1,
     "config": {
+        "reference": "refs/dense.py",
         "model": MODEL, "program_fixed": {"norm_eps": 1e-6},
         "serving": {"kv": {"layout": "paged", "page_size": 16},
                     "sata": {"decode": {"mode": "on", "block": 16}}},
@@ -38,7 +41,13 @@ SPEC = {
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
-def spec(**cell):
+def spec(reference: str = "", **cell):
+    """The tiny cell, with its reference module loaded as ``load_spec``
+    loads a configuration's; ``reference`` (a path under
+    ``chipbench/``) names another module in its place."""
     s = copy.deepcopy(SPEC)
     s["cell"].update(cell)
+    if reference:
+        s["config"]["reference"] = reference
+    s["reference"] = run.load_reference(s["config"]["reference"])
     return s

@@ -1,8 +1,9 @@
 """Random weights from a seed, made on the device in one jitted call.
 
-The benchmark makes the weights itself, so that the reference
-(``reference.py``) checks the program against numbers the program did
-not make.  Only the layout (leaf names, shapes, dtypes) comes from the
+The benchmark makes the weights itself, so that the reference module
+a configuration names (its ``"reference"``, ``refs/dense.py`` for the
+dense decoders) checks the program against numbers the program did not
+make.  Only the layout (leaf names, shapes, dtypes) comes from the
 program, as ``jax.eval_shape`` of its initialiser.
 
 Draws: the token embedding ``N(0, 0.02²)`` (both models' published
